@@ -44,6 +44,9 @@ ART = Path(__file__).resolve().parents[1] / "artifacts" / "bench"
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import paper_benches
     from benchmarks.paper_benches import ALL_BENCHES
 

@@ -15,8 +15,8 @@ per-row deltas of the newest entry against the previous K entries:
     policy_fast/M2048xN256       1234.5       -2.1%        +0.4%     5
 
 Also home to ``cost_columns``: the small normalizer that turns an XLA
-``compiled.cost_analysis()`` (a dict on some backends, a singleton
-list of dicts on others) plus a measured lower+compile wall time into
+``compiled.cost_analysis()`` dict plus a measured lower+compile wall
+time (persistent compilation cache off, so it is a compile) into
 the flat ``{"compile_ms", "flops", "bytes_accessed"}`` dict benches
 stamp onto their rows via ``paper_benches.EXTRAS``.
 """
@@ -151,16 +151,18 @@ def render_trend(history: list, last: int = 5, only=()) -> str:
 def cost_columns(fn, *args) -> dict:
     """Lower+compile `fn(*args)` and normalize XLA's cost analysis into
     flat row columns: compile_ms (measured lower->compile wall),
-    flops, bytes_accessed (0.0 when the backend reports neither)."""
+    flops, bytes_accessed (0.0 when the backend reports neither).
+    The persistent compilation cache is off around the compile, so
+    compile_ms never times a cache read."""
     import jax
 
-    t0 = time.perf_counter()
-    compiled = jax.jit(fn).lower(*args).compile()
-    compile_ms = (time.perf_counter() - t0) * 1e3
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    ca = ca or {}
+    from repro.compile_cache import compile_cache_off
+
+    with compile_cache_off():
+        t0 = time.perf_counter()
+        compiled = jax.jit(fn).lower(*args).compile()
+        compile_ms = (time.perf_counter() - t0) * 1e3
+    ca = compiled.cost_analysis() or {}
     return {
         "compile_ms": round(compile_ms, 3),
         "flops": float(ca.get("flops", 0.0)),

@@ -45,10 +45,7 @@ from typing import Callable, Dict, Iterable, List, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-try:  # jax >= 0.4.35 exposes the stable surface
-    from jax.extend import core as jcore
-except ImportError:  # pragma: no cover - older jax
-    from jax import core as jcore  # type: ignore
+from jax.extend import core as jcore
 
 # Primitives that reach back to the host from inside a jitted program.
 CALLBACK_PRIMITIVES = {

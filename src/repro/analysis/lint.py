@@ -10,9 +10,8 @@ general style (ruff covers that; see ``[tool.ruff]`` in pyproject.toml):
                   expression in a hot-path package -- an O(n) unrolled
                   trace where ``lax.scan``/``vmap`` belongs.
   pltpu-import    direct ``jax.experimental.pallas.tpu`` import outside
-                  ``kernels/compat.py``: the compat shim exists because
-                  the pltpu API drifts across JAX versions (PR 1 found
-                  27 kernel tests broken by exactly this).
+                  ``kernels/compat.py``, the one module that names the
+                  pltpu API, so a rename upstream costs one edit.
   np-in-scan      ``np.`` reference inside a function passed to
                   ``lax.scan`` / ``while_loop`` / ``fori_loop`` /
                   ``cond``: numpy silently constant-folds under trace

@@ -25,9 +25,9 @@ from jax.experimental import checkify
 DEFAULT_CHECKS = checkify.float_checks | checkify.index_checks
 
 # The fleet simulators vmap the per-instance program, and checkify's
-# OOB rule cannot instrument a *batched* scatter (jax<=0.4.37 raises
-# IndexError from the error rule itself on any scatter carrying
-# operand_batching_dims), nor discharge through a vmapped while_loop.
+# OOB rule cannot instrument a *batched* scatter (the error rule itself
+# raises IndexError on any scatter carrying operand_batching_dims), nor
+# discharge through a vmapped while_loop.
 # Fleet lifts therefore run NaN + div-by-zero only; OOB coverage for
 # the identical per-instance program comes from the single-instance
 # lanes in `sanitize_smoke`, which carry the full DEFAULT_CHECKS.

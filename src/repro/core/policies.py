@@ -218,9 +218,10 @@ class CarbonIntensityPolicy:
       * "reference" -- plain jnp (default; works everywhere, vmaps).
       * "pallas"    -- the fused kernels.carbon_score.carbon_scores
         kernel: one HBM sweep of Qc/pc produces the c-matrix and the
-        per-row (min, argmin) reduction. Falls back to interpret mode
-        off-TPU (score_interpret=None -> auto) and pads internally, so
-        any M/N works. Under jit both backends produce bit-identical
+        per-row (min, argmin) reduction. With score_interpret=None
+        (auto) it is the compiled kernel on a TPU and the jnp reference
+        elsewhere (kernels/ops.py); it pads internally, so any M/N
+        works. Under jit both backends produce bit-identical
         scores, hence bit-identical actions (tests/test_score_backend).
     """
 

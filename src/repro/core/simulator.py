@@ -238,6 +238,21 @@ def _record_scan_streaming(body, state_of, carry0, T, record, stream,
     return jax.tree.map(flat, scalars), states
 
 
+def as_data(spec: NetworkSpec, graph=None):
+    """(spec, graph) behind an optimization barrier: what every run
+    computes from them is then the same whether the caller passed them
+    into `jax.jit` or closed over them. Closed over, they are XLA
+    constants, and XLA's simplifier rewrites arithmetic on constants
+    into forms that round differently (a closed-over fleet or route
+    graph loses a multiply of the score pass); the greedy fill turns
+    one ulp into a different decision, so the reference backend would
+    drift from the Pallas kernel, whose op order is fixed."""
+    pe, pc, Pe, Pc, graph = jax.lax.optimization_barrier(
+        (*spec.as_arrays(), graph)
+    )
+    return NetworkSpec(pe=pe, pc=pc, Pe=Pe, Pc=Pc), graph
+
+
 def simulate(
     policy: Callable,
     spec: NetworkSpec,
@@ -323,6 +338,7 @@ def simulate(
     run -- the subsystem's standing parity anchor
     (tests/test_deadlines.py).
     """
+    spec, graph = as_data(spec, graph)
     if graph is not None:
         from repro.network.sim import simulate_network
 

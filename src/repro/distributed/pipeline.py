@@ -86,9 +86,7 @@ def pipeline_apply(
         )
         return outs
 
-    from repro.distributed.compat import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis), P()),    # params sharded by stage; x replicated

@@ -50,7 +50,11 @@ def _kernel(
 
     # running row min/argmin of Qc
     blk_min = jnp.min(qc, axis=1, keepdims=True)           # [bm,1]
-    blk_arg = jnp.argmin(qc, axis=1).astype(jnp.float32)[:, None] + i_n * bn
+    # first column attaining the min (jnp.argmin's tie rule), spelled
+    # out: Mosaic's argmin does not promise which tied index it returns
+    col = jax.lax.broadcasted_iota(jnp.int32, qc.shape, 1).astype(jnp.float32)
+    blk_arg = jnp.min(jnp.where(qc == blk_min, col, float(bn)), axis=1,
+                      keepdims=True) + i_n * bn
     better = blk_min < min_ref[...]
     min_ref[...] = jnp.where(better, blk_min, min_ref[...])
     arg_ref[...] = jnp.where(better, blk_arg, arg_ref[...])

@@ -1,26 +1,15 @@
-"""Pallas TPU API compatibility shims.
+"""The one import of ``jax.experimental.pallas.tpu``.
 
-JAX renamed ``pltpu.TPUCompilerParams`` to ``pltpu.CompilerParams`` (the
-old spelling survives as a deprecated alias for a few releases, and older
-releases such as 0.4.x only have the TPU-prefixed name). Feature-detect
-once here so every kernel in this package works across the installed
-range instead of hard-coding one spelling.
-
-This module is the single place allowed to import
-``jax.experimental.pallas.tpu`` (enforced by the ``pltpu-import`` lint
-rule in ``repro.analysis``): kernels pull ``CompilerParams`` / ``VMEM`` /
-``PrefetchScalarGridSpec`` from here, so an upstream rename costs one
-edit instead of one per kernel.
+Kernels pull ``CompilerParams`` / ``VMEM`` / ``PrefetchScalarGridSpec``
+from here (enforced by the ``pltpu-import`` lint rule in
+``repro.analysis``), so a rename in the Pallas TPU API costs one edit
+instead of one per kernel. Written for the pinned JAX (pyproject.toml).
 """
 from __future__ import annotations
 
 import jax.experimental.pallas.tpu as pltpu  # lint: allow=pltpu-import
 
-if hasattr(pltpu, "CompilerParams"):
-    CompilerParams = pltpu.CompilerParams
-else:  # jax <= 0.4.x
-    CompilerParams = pltpu.TPUCompilerParams
-
+CompilerParams = pltpu.CompilerParams
 VMEM = pltpu.VMEM
 PrefetchScalarGridSpec = pltpu.PrefetchScalarGridSpec
 

@@ -1,9 +1,16 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On TPU the real kernels run; everywhere else (this CPU container) they
-execute in Pallas interpret mode when `interpret=None` (auto) resolves to
-True. The contracts match kernels/ref.py exactly (see tests/test_kernels.py
-shape/dtype sweeps).
+Dispatch with `interpret=None` (auto), decided by `jax.default_backend()`:
+
+* on a TPU every wrapper runs the compiled Mosaic kernel;
+* elsewhere the score kernels (`carbon_scores`, `route_scores`) run their
+  bit-identical jnp reference, and the LM kernels (flash attention,
+  decode, SSD) run in Pallas interpret mode.
+
+`interpret=True` forces the interpret-mode kernel (the parity tests'
+oracle); `interpret=False` forces the compiled kernel (what a TPU compile
+rehearsal on a CPU host asks for). The contracts match kernels/ref.py
+exactly (see tests/test_kernels.py shape/dtype sweeps).
 """
 from __future__ import annotations
 

@@ -63,7 +63,11 @@ def _kernel(
 
     # running row min/argmin of rc
     blk_min = jnp.min(rc, axis=1, keepdims=True)              # [bm,1]
-    blk_arg = jnp.argmin(rc, axis=1).astype(jnp.float32)[:, None] + i_l * bl
+    # first column attaining the min (jnp.argmin's tie rule), spelled
+    # out: Mosaic's argmin does not promise which tied index it returns
+    col = jax.lax.broadcasted_iota(jnp.int32, rc.shape, 1).astype(jnp.float32)
+    blk_arg = jnp.min(jnp.where(rc == blk_min, col, float(bl)), axis=1,
+                      keepdims=True) + i_l * bl
     better = blk_min < min_ref[...]
     min_ref[...] = jnp.where(better, blk_min, min_ref[...])
     arg_ref[...] = jnp.where(better, blk_arg, arg_ref[...])

@@ -48,15 +48,6 @@ _COLLECTIVES = (
 )
 
 
-def _cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() returns a dict on new jax, a list of
-    per-computation dicts on jax<=0.4.x -- normalize to one dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else {}
-    return cost
-
-
 def parse_collective_bytes(hlo_text: str):
     """Sums result-shape bytes of every collective op in post-SPMD HLO.
 
@@ -162,7 +153,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         out_shard = (None, c_shard)
 
     rules = sh.activation_rule_table(mesh, cfg, seq_parallel=seq_parallel)
-    with mesh, dist_api.activation_rules(
+    with jax.set_mesh(mesh), dist_api.activation_rules(
         rules, mesh=mesh, dp_axes=sh.dp_axes(mesh), ep_axis="model"
     ):
         jfn = jax.jit(fn, in_shardings=in_shard, out_shardings=out_shard)
@@ -170,7 +161,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         compiled = lowered.compile()
 
     mem = compiled.memory_analysis()
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll_bytes, coll_counts = parse_collective_bytes(hlo)
     t1 = time.time()
@@ -299,13 +290,13 @@ def _cell_costs(cfg, shape_name: str, multi_pod: bool,
         out_shard = (None, c_shard)
 
     rules = sh.activation_rule_table(mesh, cfg, seq_parallel=seq_parallel)
-    with mesh, dist_api.activation_rules(
+    with jax.set_mesh(mesh), dist_api.activation_rules(
         rules, mesh=mesh, dp_axes=sh.dp_axes(mesh), ep_axis="model"
     ):
         compiled = jax.jit(
             fn, in_shardings=in_shard, out_shardings=out_shard
         ).lower(*args).compile()
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis()
     coll_bytes, _ = parse_collective_bytes(compiled.as_text())
     return {
         "flops": float(cost.get("flops", 0.0)),

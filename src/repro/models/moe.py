@@ -172,7 +172,6 @@ def moe_ep_shardmap(
     the ep axis (e.g. decode steps with S=1) -- see apply_moe.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.distributed.compat import shard_map
 
     B, S, D = x.shape
     E = p["router"].shape[1]
@@ -253,7 +252,7 @@ def moe_ep_shardmap(
         return y.reshape(b_loc, s_loc, D).astype(xb.dtype)
 
     w_gate_arg = p.get("w_gate", p["w_in"])  # placeholder when ungated
-    fn = shard_map(
+    fn = jax.shard_map(
         block,
         mesh=mesh,
         in_specs=(

@@ -164,4 +164,6 @@ class StaticRoutePolicy:
             kwargs["deadline_view"] = deadline_view
         act = self.inner(state, spec, Ce, Cc, arrivals, key, **kwargs)
         onehot = jax.nn.one_hot(graph.primary, graph.L, dtype=act.d.dtype)
-        return NetAction(dt=act.d @ onehot, w=act.w)
+        # HIGHEST keeps the counts exact on a TPU (network/transfer.py)
+        dt = jnp.matmul(act.d, onehot, precision=jax.lax.Precision.HIGHEST)
+        return NetAction(dt=dt, w=act.w)

@@ -24,8 +24,10 @@ against it. Consequences, all covered by tests/test_network.py:
     bit-identical to the link-free simulator.
 
 Deliveries are aggregated per destination cloud with a one-hot matmul
-(exact for integral counts in float32), so the whole step is dense
-linear algebra that scans and vmaps.
+at HIGHEST precision, so the whole step is dense linear algebra that
+scans and vmaps. The precision is what keeps it exact for integral
+counts: a TPU runs a DEFAULT-precision f32 matmul as one bfloat16 pass,
+which rounds any count above 256 to 8 significant bits.
 """
 from __future__ import annotations
 
@@ -61,6 +63,19 @@ def init_links(M: int, L: int, dtype=DTYPE) -> LinkState:
     return LinkState(Qt=z, prog=z)
 
 
+def sum_rows_in_order(x: Array) -> Array:
+    """x[0] + x[1] + ... + x[M-1], added in index order. A reduce
+    leaves its order of additions to the compiler, which on a TPU
+    derives it from the array's layout; two programs that run this step
+    with different neighbours (the Pallas score kernel pins the layout
+    of its operands, the jnp reference does not) would then round the
+    fractional transfer demand differently, and one ulp of progress
+    flips a `floor` into a different delivery. The loop fixes the order
+    for every program and every backend; it is the order the CPU's
+    reduce already uses, so CPU results do not move."""
+    return jax.lax.fori_loop(1, x.shape[0], lambda m, acc: acc + x[m], x[0])
+
+
 def step_links(
     ls: LinkState, graph: LinkGraph, dt: Array, bw_scale: Array | None = None
 ) -> Tuple[LinkState, Array]:
@@ -81,7 +96,7 @@ def step_links(
             bw = jnp.where(bw_scale > 0.0, graph.bw * bw_scale, 0.0)
         Qt = ls.Qt + dt
         demand = Qt * graph.size[:, None] - ls.prog      # [M, L] work left
-        total = jnp.sum(demand, axis=0)                  # [L]
+        total = sum_rows_in_order(demand)                # [L]
         ratio = jnp.minimum(1.0, bw / jnp.maximum(total, _TINY))
         prog = ls.prog + demand * ratio
         # Clamp at 0 on both sides of the delivery: cancellation in
@@ -103,9 +118,10 @@ def step_links(
 
 
 def land_in_clouds(delivered: Array, graph: LinkGraph, N: int) -> Array:
-    """Aggregates route deliveries [M,L] into cloud arrivals [M,N]."""
+    """Aggregates route deliveries [M,L] into cloud arrivals [M,N],
+    exactly (see the module doc on the matmul precision)."""
     onehot = jax.nn.one_hot(graph.dest, N, dtype=delivered.dtype)  # [L, N]
-    return delivered @ onehot
+    return jnp.matmul(delivered, onehot, precision=jax.lax.Precision.HIGHEST)
 
 
 def transfer_energy(graph: LinkGraph, dt: Array) -> Array:

@@ -51,6 +51,7 @@ from repro.core.queueing import (
     init_state,
 )
 from repro.core.queueing import step as queue_step
+from repro.core.simulator import as_data
 
 # Latency histogram buckets (microseconds), Prometheus-style with a
 # terminal +Inf bucket appended by the exporter.
@@ -87,6 +88,12 @@ class ServeReport(NamedTuple):
     age_p95: float = 0.0       #   read against the configured deadline
     age_p99: float = 0.0       #   (the queue-age-vs-deadline export)
     age_over_deadline_frac: float = 0.0  # slots with age > min deadline
+    # the step's carried state after the last slot (NetworkState, or
+    # (NetworkState, DeadlineState) when deadline-aware) and the
+    # per-slot emissions -- what the batch simulator's final Qe/Qc and
+    # `emissions` series are compared against
+    final_state: object = None
+    slot_emissions: np.ndarray = None  # [slots]
 
 
 def latency_percentiles(lat_us) -> tuple:
@@ -124,15 +131,16 @@ def make_serve_step(policy, spec: NetworkSpec, carbon_source,
     def step(state, t):
         if deadlines is not None:
             state, dstate = state
+        spec_t, _ = as_data(spec)  # as in simulate
         Ce, Cc = carbon_source(t, k_carbon)
         a = arrival_source(t, k_arrive)
         k_t = jax.random.fold_in(k_policy, t)
         if deadlines is None:
-            act: Action = policy(state, spec, Ce, Cc, a, k_t)
+            act: Action = policy(state, spec_t, Ce, Cc, a, k_t)
         else:
-            act = policy(state, spec, Ce, Cc, a, k_t,
+            act = policy(state, spec_t, Ce, Cc, a, k_t,
                          deadline_view=deadline_view(deadlines, dstate))
-        C_t = emissions(spec, act, Ce, Cc)
+        C_t = emissions(spec_t, act, Ce, Cc)
         metrics = (
             C_t,
             jnp.sum(a),
@@ -348,6 +356,7 @@ def serve_loop(policy, spec: NetworkSpec, carbon_source, arrival_source,
     ages = _AgeFifo()
     lat = np.zeros(T)
     backlog = np.zeros(T)
+    slot_emissions = np.zeros(T)
     queue_age = np.zeros(T, np.int64)
     totals = {"arrived": 0.0, "dispatched": 0.0, "processed": 0.0,
               "emissions": 0.0, "missed": 0.0, "shed": 0.0}
@@ -374,6 +383,7 @@ def serve_loop(policy, spec: NetworkSpec, carbon_source, arrival_source,
         totals["missed"] += missed_t
         totals["shed"] += shed_t
         backlog[t] = bl
+        slot_emissions[t] = em_t
         # shed arrivals never enter the queue; missed tasks leave it by
         # expiry -- both must flow through the age FIFO or the gauge
         # reads phantom tasks (no-ops when the deadline layer is off)
@@ -413,13 +423,16 @@ def serve_loop(policy, spec: NetworkSpec, carbon_source, arrival_source,
         shed_total=totals["shed"],
         age_p50=age_p50, age_p95=age_p95, age_p99=age_p99,
         age_over_deadline_frac=over_frac,
+        final_state=state,
+        slot_emissions=slot_emissions,
     )
     if exporter is not None:
         exporter.close(report)
     return report
 
 
-def _demo_spec(M: int, N: int, seed: int) -> NetworkSpec:
+def demo_spec(M: int, N: int, seed: int) -> NetworkSpec:
+    """The synthetic M-type x N-cloud instance `main` serves."""
     rng = np.random.default_rng(seed)
     return NetworkSpec(
         pe=rng.uniform(1, 8, M).astype(np.float32),
@@ -435,12 +448,14 @@ def main(argv=None) -> ServeReport:
     and (with `--outdir`) leaves live-exported Prometheus + JSONL
     behind for parse validation. REPRO_SMOKE=1 shrinks the instance;
     even smoke pushes >= 10^4 synthetic tasks through admission."""
+    from repro.compile_cache import enable_compile_cache
     from repro.core import (
         CarbonIntensityPolicy,
         UKRegionalTraceSource,
         UniformArrivals,
     )
 
+    enable_compile_cache()
     smoke = os.environ.get("REPRO_SMOKE") == "1"
     ap = argparse.ArgumentParser()
     ap.add_argument("--slots", type=int, default=24 if smoke else 64)
@@ -473,7 +488,7 @@ def main(argv=None) -> ServeReport:
         )
         policy = SlackThresholdPolicy(V=0.05)
 
-    spec = _demo_spec(args.types, args.clouds, args.seed)
+    spec = demo_spec(args.types, args.clouds, args.seed)
     report = serve_loop(
         policy,
         spec,

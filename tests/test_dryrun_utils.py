@@ -39,12 +39,12 @@ def test_parse_ignores_non_collectives():
 
 
 def test_rule_engine_divisibility_fallback():
-    import jax
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed import sharding as sh
+    from repro.launch.mesh import auto_mesh
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
 
     eng = sh.RuleEngine(mesh)
     # both divide trivially on a unit mesh
@@ -57,9 +57,10 @@ def test_param_shardings_cover_all_leaves():
 
     from repro.configs import registry
     from repro.distributed import sharding as sh
+    from repro.launch.mesh import auto_mesh
     from repro.models import build_model
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     for aid in ("qwen1_5_0_5b", "arctic_480b", "jamba_1_5_large_398b",
                 "seamless_m4t_medium"):
         cfg = registry.get_smoke_config(aid)
@@ -87,7 +88,8 @@ def test_mini_dryrun_subprocess(tmp_path):
         from repro.models import build_model
         from repro.optim.adamw import AdamW, make_train_step
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((4, 2), ("data", "model"))
         cfg = registry.get_smoke_config("internlm2_20b")
         model = build_model(cfg)
         pspecs = model.param_specs()
@@ -106,14 +108,13 @@ def test_mini_dryrun_subprocess(tmp_path):
         flops = {}
         for sp in (False, True):  # baseline + sequence-parallel rules
             rules = sh.activation_rule_table(mesh, cfg, seq_parallel=sp)
-            with mesh, dist_api.activation_rules(rules, mesh=mesh,
+            with jax.set_mesh(mesh), dist_api.activation_rules(rules, mesh=mesh,
                                                  dp_axes=("data",)):
                 compiled = jax.jit(
                     fn, in_shardings=(p_shard, o_shard, b_shard),
                     out_shardings=(p_shard, o_shard, None)
                 ).lower(pspecs, ospecs, batch).compile()
-            from repro.launch.dryrun import _cost_dict
-            flops[sp] = float(_cost_dict(compiled).get("flops", 0))
+            flops[sp] = float(compiled.cost_analysis().get("flops", 0))
         print(json.dumps({"flops": flops[False], "flops_sp": flops[True]}))
     """)
     out = subprocess.run(

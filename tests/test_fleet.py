@@ -132,3 +132,29 @@ def test_fleet_carbon_policy_beats_queue_policy_on_average():
     mean_carb = float(carb.cum_emissions[:, -1].mean())
     mean_base = float(base.cum_emissions[:, -1].mean())
     assert mean_carb < mean_base
+
+
+@pytest.mark.parametrize("kind,F,T", [("diurnal", 16, 96),
+                                      ("congested-uplink", 8, 48)])
+def test_fleet_run_does_not_depend_on_how_the_fleet_is_passed(kind, F, T):
+    """Closed over under jit (XLA constants it could fold on the host)
+    or passed as an argument, the fleet gives the same run bitwise."""
+    from repro.configs.fleet_scenarios import build_network_fleet
+    from repro.network import NetworkAwareDPPPolicy
+
+    if kind == "diurnal":
+        fleet = build_fleet([kind], per_kind=F, Tc=96, seed=0)
+        pol = CarbonIntensityPolicy(V=0.05)
+    else:
+        fleet = build_network_fleet([kind], per_kind=F, Tc=96, seed=0)
+        pol = NetworkAwareDPPPolicy(V=0.1)
+    key = jax.random.PRNGKey(0)
+    closed = jax.jit(lambda k: simulate_fleet(
+        pol, fleet, T, k, record="summary"))(key)
+    passed = jax.jit(lambda fl, k: simulate_fleet(
+        pol, fl, T, k, record="summary"))(fleet, key)
+    for field in ("Qe", "Qc", "emissions"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(closed, field)),
+            np.asarray(getattr(passed, field)), err_msg=field,
+        )

@@ -27,6 +27,7 @@ from repro.network import (
     make_graph,
     step_links,
 )
+from repro.network.transfer import land_in_clouds, sum_rows_in_order
 
 jax.config.update("jax_enable_x64", False)
 
@@ -266,6 +267,61 @@ def test_full_simulation_conserves_tasks():
     deliv = np.asarray(res.delivered).sum(axis=1)
     qt_end = np.asarray(res.Qt)[:, -1].sum(axis=(1, 2))
     np.testing.assert_allclose(disp, deliv + qt_end, rtol=0, atol=1e-3)
+
+
+def test_full_simulation_cloud_queues_conserve_exactly():
+    """Every slot, each lane's cloud backlog changes by exactly
+    delivered - processed: the landing matmul loses and makes no task."""
+    fleet = build_network_fleet(["congested-uplink"], per_kind=2, Tc=48)
+    res = simulate_fleet(
+        NetworkAwareDPPPolicy(V=0.1), fleet, 60, jax.random.PRNGKey(1),
+    )
+    qc = np.asarray(res.Qc, np.float64).sum(axis=(2, 3))   # [F, T]
+    prev = np.concatenate([np.zeros_like(qc[:, :1]), qc[:, :-1]], axis=1)
+    change = np.asarray(res.delivered, np.float64) - np.asarray(
+        res.processed, np.float64)
+    np.testing.assert_array_equal(qc - prev, change)
+
+
+def _dot_precisions(fn, *args):
+    return [
+        eqn.params["precision"]
+        for eqn in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+        if eqn.primitive.name == "dot_general"
+    ]
+
+
+@pytest.mark.parametrize("site", ["land_in_clouds", "static_route"])
+def test_count_matmuls_ask_for_highest_precision(site):
+    """Task counts go through one-hot matmuls; a TPU runs a DEFAULT
+    precision f32 matmul as one bfloat16 pass, which rounds counts above
+    256. Both sites must ask for HIGHEST (exact for integral counts)."""
+    rng = np.random.default_rng(3)
+    M, N = 4, 3
+    spec, state, Ce, Cc = _random_instance(rng, M, N)
+    g = direct_graph(M, N)
+    if site == "land_in_clouds":
+        fn = lambda d: land_in_clouds(d, g, N)  # noqa: E731
+        args = (jnp.zeros((M, g.L)),)
+    else:
+        pol = StaticRoutePolicy(CarbonIntensityPolicy(V=0.05))
+        fn = lambda Qt: pol(state, spec, Ce, Cc, None, None,  # noqa: E731
+                            graph=g, Qt=Qt).dt
+        args = (jnp.zeros((M, g.L)),)
+    highest = jax.lax.Precision.HIGHEST
+    assert _dot_precisions(fn, *args) == [(highest, highest)]
+
+
+def test_sum_rows_in_order_adds_in_index_order():
+    """((x0 + x1) + x2) + x3, whatever the compiler would pick for a
+    reduce: the pairwise order gives 0 on these rows, index order 1."""
+    x = jnp.asarray([[1e8], [1.0], [-1e8], [1.0]], jnp.float32)
+    assert float(jax.jit(sum_rows_in_order)(x)[0]) == 1.0
+    lanes = jnp.stack([x, x[::-1]])
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(jax.vmap(sum_rows_in_order))(lanes))[:, 0],
+        [1.0, 0.0],
+    )
 
 
 # ------------------------------------------------- stacked-topology fleet

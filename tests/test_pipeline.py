@@ -19,7 +19,8 @@ def test_pipeline_matches_reference_subprocess():
         from repro.distributed.pipeline import pipeline_apply, \\
             pipeline_reference
 
-        mesh = jax.make_mesh((4,), ("stage",))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((4,), ("stage",))
         n_stages, n_micro, mb, d = 4, 6, 2, 8
         key = jax.random.PRNGKey(0)
         w = jax.random.normal(key, (n_stages, d, d)) * 0.3

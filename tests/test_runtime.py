@@ -186,7 +186,8 @@ def test_compressed_psum_multidevice_subprocess():
         from jax.sharding import PartitionSpec as P
         from repro.distributed import compression as comp
 
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((8,), ("data",))
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 64)) * 0.1
 
         def body(g_blk):
@@ -195,8 +196,7 @@ def test_compressed_psum_multidevice_subprocess():
             summed, state = comp.compressed_psum_grads(grads, state, "data")
             return summed["w"]
 
-        from repro.distributed.compat import shard_map
-        fn = shard_map(body, mesh=mesh, in_specs=P("data"),
+        fn = jax.shard_map(body, mesh=mesh, in_specs=P("data"),
                        out_specs=P(), check_vma=False)
         got = fn(g)
         want = jnp.sum(g, axis=0)
@@ -238,7 +238,8 @@ def test_elastic_restart_on_fewer_devices():
         from repro.models import build_model
         from repro.optim.adamw import AdamW, make_train_step
 
-        mesh = jax.make_mesh(({n},), ("data",))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh(({n},), ("data",))
         cfg = registry.get_smoke_config("qwen1_5_0_5b")
         model = build_model(cfg)
         opt = AdamW(lr=1e-3)

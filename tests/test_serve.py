@@ -203,7 +203,8 @@ class TestLiveExport:
 
 
 class TestSmokeCLI:
-    def test_main_smoke(self, tmp_path, monkeypatch, capsys):
+    def test_main_smoke(self, tmp_path, monkeypatch, capsys,
+                        isolated_compile_cache):
         from repro.serve.loop import main
 
         monkeypatch.setenv("REPRO_SMOKE", "1")

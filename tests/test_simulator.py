@@ -183,3 +183,70 @@ def test_simulation_deterministic_given_key():
     np.testing.assert_array_equal(
         np.asarray(r1.cum_emissions), np.asarray(r2.cum_emissions)
     )
+
+
+def _lane(fleet, f):
+    """Lane f of a stacked network fleet as (spec, graph, carbon, arrivals)."""
+    from repro.core import TableCarbonSource
+    from repro.core.queueing import NetworkSpec
+
+    spec = NetworkSpec(pe=fleet.spec.pe[f], pc=fleet.spec.pc[f],
+                       Pe=fleet.spec.Pe[f], Pc=fleet.spec.Pc[f])
+    graph = jax.tree.map(lambda x: x[f], fleet.graph)
+    return (spec, graph, TableCarbonSource(table=fleet.carbon[f]),
+            UniformArrivals(M=spec.M, amax=300))
+
+
+@pytest.mark.parametrize("entry", ["simulate-wan", "serve-step"])
+def test_run_does_not_depend_on_how_spec_and_graph_are_passed(entry):
+    """Closed over under jit (XLA constants it could rewrite) or passed
+    as arguments, the spec and route graph give the same run bitwise:
+    through `simulate` (a WAN lane, whose closed-over graph changed the
+    final queues before `as_data`) and through the served step, which
+    closes over its spec by construction."""
+    from repro.configs.fleet_scenarios import build_network_fleet
+    from repro.core.queueing import NetworkSpec, init_state
+    from repro.network import NetworkAwareDPPPolicy
+    from repro.serve import make_serve_step
+
+    key = jax.random.PRNGKey(0)
+    T = 48
+
+    def passed(pol, spec, carbon, arrivals, graph=None):
+        def run(pe, pc, Pe, Pc, g, k):
+            return simulate(pol, NetworkSpec(pe=pe, pc=pc, Pe=Pe, Pc=Pc),
+                            carbon, arrivals, T, k, graph=g,
+                            record="summary")
+        return jax.jit(run)(spec.pe, spec.pc, spec.Pe, spec.Pc, graph, key)
+
+    if entry == "simulate-wan":
+        fleet = build_network_fleet(["congested-uplink"], per_kind=4,
+                                    Tc=96, seed=0)
+        pol = NetworkAwareDPPPolicy(V=0.1)
+        for f in range(fleet.F):
+            spec, graph, carbon, arrivals = _lane(fleet, f)
+            closed = jax.jit(lambda k: simulate(
+                pol, spec, carbon, arrivals, T, k, graph=graph,
+                record="summary"))(key)
+            ref = passed(pol, spec, carbon, arrivals, graph)
+            for field in ("Qe", "Qc", "Qt", "emissions"):
+                np.testing.assert_array_equal(
+                    np.asarray(getattr(closed, field)),
+                    np.asarray(getattr(ref, field)),
+                    err_msg=f"lane {f} {field}")
+    else:
+        spec = paper_spec()
+        pol = CarbonIntensityPolicy(V=0.05)
+        carbon = UKRegionalTraceSource(N=spec.N)
+        arrivals = UniformArrivals(M=spec.M, amax=400)
+        step = make_serve_step(pol, spec, carbon, arrivals, key)
+        state = init_state(spec.M, spec.N)
+        for t in range(T):
+            state, _ = step(state, jnp.int32(t))
+        # the decisions, i.e. the queues; the per-slot emission sums
+        # of the two programs may round apart by an ulp on the CPU
+        ref = passed(pol, spec, carbon, arrivals)
+        np.testing.assert_array_equal(np.asarray(state.Qe),
+                                      np.asarray(ref.Qe[-1]))
+        np.testing.assert_array_equal(np.asarray(state.Qc),
+                                      np.asarray(ref.Qc[-1]))
