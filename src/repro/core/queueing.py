@@ -20,6 +20,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.telemetry.profile import phase
+
 Array = jax.Array
 
 # Queue lengths are kept in float32 on purpose: counts are integral by
@@ -104,9 +106,10 @@ def emissions(spec: NetworkSpec, action: Action, Ce: Array, Cc: Array) -> Array:
     Ce: scalar edge carbon intensity; Cc: [N] cloud carbon intensities.
     """
     pe, pc, _, _ = spec.as_arrays()
-    return Ce * edge_energy(pe, action.d) + jnp.sum(
-        Cc * cloud_energy(pc, action.w)
-    )
+    with phase("emissions"):
+        return Ce * edge_energy(pe, action.d) + jnp.sum(
+            Cc * cloud_energy(pc, action.w)
+        )
 
 
 def is_feasible(spec: NetworkSpec, action: Action, atol: float = 1e-3) -> Array:
@@ -131,9 +134,10 @@ def step(state: NetworkState, action: Action, arrivals: Array) -> NetworkState:
     in this repo never overshoot (they clip to queue lengths), but the
     dynamics stay faithful to the equations.
     """
-    d_sum = jnp.sum(action.d, axis=1)  # [M]
-    Qe = jnp.maximum(state.Qe - d_sum, 0.0) + arrivals
-    Qc = jnp.maximum(state.Qc - action.w, 0.0) + action.d
+    with phase("queue_update"):
+        d_sum = jnp.sum(action.d, axis=1)  # [M]
+        Qe = jnp.maximum(state.Qe - d_sum, 0.0) + arrivals
+        Qc = jnp.maximum(state.Qc - action.w, 0.0) + action.d
     return NetworkState(Qe=Qe, Qc=Qc)
 
 

@@ -23,6 +23,7 @@ from repro.core.queueing import (
     init_state,
     step,
 )
+from repro.telemetry.profile import phase
 from repro.telemetry.stream import split_telemetry, stream_flush
 from repro.telemetry.taps import (
     TelemetryProbe,
@@ -379,8 +380,10 @@ def simulate(
 
     def body(carry, t):
         state, fcarry, tap, dstate = carry
-        Ce, Cc = carbon_source(t, k_carbon)
-        a = arrival_source(t, k_arrive)
+        with phase("carbon"):
+            Ce, Cc = carbon_source(t, k_carbon)
+        with phase("arrivals"):
+            a = arrival_source(t, k_arrive)
         k_t = jax.random.fold_in(k_policy, t)
         pkw = {}
         if deadlines is not None:
@@ -407,11 +410,12 @@ def simulate(
             # Same queue update as `step`, with arrivals replaced by
             # (admitted - expired): bitwise `+ a` under the
             # no_deadlines anchor (admitted == a, expired == +0.0).
-            nxt = NetworkState(
-                Qe=jnp.maximum(state.Qe - d_sum, 0.0)
-                + admitted - expired,
-                Qc=jnp.maximum(state.Qc - act.w, 0.0) + act.d,
-            )
+            with phase("queue_update"):
+                nxt = NetworkState(
+                    Qe=jnp.maximum(state.Qe - d_sum, 0.0)
+                    + admitted - expired,
+                    Qc=jnp.maximum(state.Qc - act.w, 0.0) + act.d,
+                )
             missed = jnp.sum(expired)
             shed = jnp.sum(shed_v)
         out = (

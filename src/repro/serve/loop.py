@@ -15,7 +15,8 @@ Observability contract (ISSUE 9 / DESIGN.md §Live observability):
   `block_until_ready`, recorded per slot; percentiles (p50/p95/p99,
   `np.percentile` linear interpolation) exclude the first `warmup`
   slots, where the call pays XLA compilation;
-* throughput -- tasks/sec over the run's wall clock;
+* throughput -- tasks/sec over slots[warmup:], from the clock call
+  before the first of them to the loop's closing call;
 * queue age -- a host-side FIFO of (arrival slot, count) drained
   oldest-first by each slot's processing attempts: the age of the
   oldest unserved task, per slot, plus its max over the run;
@@ -30,6 +31,11 @@ The clock is injectable (`clock=` callable returning seconds) and the
 loop calls it in a fixed pattern -- once before the loop, twice per
 slot (around the step), once after -- so tests drive it with a fake
 and get deterministic histograms.
+
+Under a running profiler each slot's host work shows as the four
+`telemetry.profile.HOST_SPANS`: `serve.dispatch` and `serve.sync`
+inside the latency bracket, `serve.pull` and `serve.bookkeeping` after
+it (`telemetry.profile.trace_to` records one).
 """
 from __future__ import annotations
 
@@ -52,6 +58,7 @@ from repro.core.queueing import (
 )
 from repro.core.queueing import step as queue_step
 from repro.core.simulator import as_data
+from repro.telemetry.profile import phase, span
 
 # Latency histogram buckets (microseconds), Prometheus-style with a
 # terminal +Inf bucket appended by the exporter.
@@ -72,7 +79,7 @@ class ServeReport(NamedTuple):
     tasks_processed: float
     total_emissions: float
     wall_s: float
-    tasks_per_sec: float   # arrived tasks / wall_s
+    tasks_per_sec: float   # arrived in slots[warmup:] / their wall time
     p50_us: float          # decision-latency percentiles over
     p95_us: float          #   slots[warmup:]
     p99_us: float
@@ -132,8 +139,10 @@ def make_serve_step(policy, spec: NetworkSpec, carbon_source,
         if deadlines is not None:
             state, dstate = state
         spec_t, _ = as_data(spec)  # as in simulate
-        Ce, Cc = carbon_source(t, k_carbon)
-        a = arrival_source(t, k_arrive)
+        with phase("carbon"):
+            Ce, Cc = carbon_source(t, k_carbon)
+        with phase("arrivals"):
+            a = arrival_source(t, k_arrive)
         k_t = jax.random.fold_in(k_policy, t)
         if deadlines is None:
             act: Action = policy(state, spec_t, Ce, Cc, a, k_t)
@@ -156,10 +165,11 @@ def make_serve_step(policy, spec: NetworkSpec, carbon_source,
         dstate, admitted, expired, shed = step_deadlines(
             deadlines, dstate, d_sum, a
         )
-        nxt = state._replace(
-            Qe=jnp.maximum(state.Qe - d_sum, 0.0) + admitted - expired,
-            Qc=jnp.maximum(state.Qc - act.w, 0.0) + act.d,
-        )
+        with phase("queue_update"):
+            nxt = state._replace(
+                Qe=jnp.maximum(state.Qe - d_sum, 0.0) + admitted - expired,
+                Qc=jnp.maximum(state.Qc - act.w, 0.0) + act.d,
+            )
         return (nxt, dstate), metrics + (
             jnp.sum(nxt.Qe) + jnp.sum(nxt.Qc),
             jnp.sum(expired),
@@ -356,44 +366,53 @@ def serve_loop(policy, spec: NetworkSpec, carbon_source, arrival_source,
     ages = _AgeFifo()
     lat = np.zeros(T)
     backlog = np.zeros(T)
+    slot_arrived = np.zeros(T)
     slot_emissions = np.zeros(T)
     queue_age = np.zeros(T, np.int64)
     totals = {"arrived": 0.0, "dispatched": 0.0, "processed": 0.0,
               "emissions": 0.0, "missed": 0.0, "shed": 0.0}
 
-    t_start = clock()
+    t_start = t_measured = clock()
     for t in range(T):
         c0 = clock()
-        state, metrics = step(state, jnp.int32(t))
-        jax.block_until_ready(metrics)
+        with span("serve.dispatch"):
+            state, metrics = step(state, jnp.int32(t))
+        with span("serve.sync"):
+            jax.block_until_ready(metrics)
         c1 = clock()
         lat[t] = (c1 - c0) * 1e6
-        missed_t = shed_t = 0.0
-        if deadlines is None:
-            em_t, arrived, dispatched, processed, bl = (
-                float(x) for x in metrics
-            )
-        else:
-            (em_t, arrived, dispatched, processed, bl,
-             missed_t, shed_t) = (float(x) for x in metrics)
-        totals["arrived"] += arrived
-        totals["dispatched"] += dispatched
-        totals["processed"] += processed
-        totals["emissions"] += em_t
-        totals["missed"] += missed_t
-        totals["shed"] += shed_t
-        backlog[t] = bl
-        slot_emissions[t] = em_t
-        # shed arrivals never enter the queue; missed tasks leave it by
-        # expiry -- both must flow through the age FIFO or the gauge
-        # reads phantom tasks (no-ops when the deadline layer is off)
-        queue_age[t] = ages.update(t, arrived - shed_t,
-                                   processed + missed_t)
-        if exporter is not None:
-            exporter.record(t, lat[t], arrived, dispatched, processed,
-                            bl, int(queue_age[t]), em_t,
-                            missed=missed_t, shed=shed_t)
-    wall_s = clock() - t_start
+        with span("serve.pull"):
+            pulled = [float(x) for x in metrics]
+        with span("serve.bookkeeping"):
+            missed_t = shed_t = 0.0
+            if deadlines is None:
+                em_t, arrived, dispatched, processed, bl = pulled
+            else:
+                (em_t, arrived, dispatched, processed, bl,
+                 missed_t, shed_t) = pulled
+            if t == warmup:
+                t_measured = c0
+            totals["arrived"] += arrived
+            totals["dispatched"] += dispatched
+            totals["processed"] += processed
+            totals["emissions"] += em_t
+            totals["missed"] += missed_t
+            totals["shed"] += shed_t
+            slot_arrived[t] = arrived
+            backlog[t] = bl
+            slot_emissions[t] = em_t
+            # shed arrivals never enter the queue; missed tasks leave it
+            # by expiry -- both must flow through the age FIFO or the
+            # gauge reads phantom tasks (no-ops when the deadline layer
+            # is off)
+            queue_age[t] = ages.update(t, arrived - shed_t,
+                                       processed + missed_t)
+            if exporter is not None:
+                exporter.record(t, lat[t], arrived, dispatched, processed,
+                                bl, int(queue_age[t]), em_t,
+                                missed=missed_t, shed=shed_t)
+    t_end = clock()
+    wall_s = t_end - t_start
 
     p50, p95, p99, mean = latency_percentiles(lat[warmup:])
     age_p50, age_p95, age_p99 = (
@@ -413,7 +432,8 @@ def serve_loop(policy, spec: NetworkSpec, carbon_source, arrival_source,
         tasks_processed=totals["processed"],
         total_emissions=totals["emissions"],
         wall_s=wall_s,
-        tasks_per_sec=totals["arrived"] / max(wall_s, 1e-12),
+        tasks_per_sec=float(slot_arrived[warmup:].sum())
+        / max(t_end - t_measured, 1e-12),
         p50_us=p50, p95_us=p95, p99_us=p99, mean_us=mean,
         max_queue_age=int(queue_age.max()),
         latency_us=lat,

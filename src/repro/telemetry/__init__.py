@@ -30,7 +30,7 @@ from repro.telemetry.export import (
     write_run,
 )
 from repro.telemetry.monitors import MONITORS, monitor_conditions
-from repro.telemetry.profile import PHASES, phase, trace_to
+from repro.telemetry.profile import HOST_SPANS, PHASES, phase, span, trace_to
 from repro.telemetry.stream import (
     StreamChannel,
     StreamConfig,
@@ -55,6 +55,7 @@ from repro.telemetry.taps import (
 __all__ = [
     "MONITORS",
     "METRICS",
+    "HOST_SPANS",
     "PHASES",
     "FollowedRun",
     "MetricSpec",
@@ -76,6 +77,7 @@ __all__ = [
     "monitor_conditions",
     "oracle_gap_series",
     "phase",
+    "span",
     "step_taps",
     "to_chrome_trace",
     "to_jsonl",

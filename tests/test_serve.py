@@ -87,6 +87,17 @@ class TestLatencyAccounting:
         assert rep.wall_s == 2 * T + 1
         assert rep.slots == T and rep.warmup == 2
 
+    def test_tasks_per_sec_over_slots_after_warmup(self):
+        """6t tasks arrive in slot t. Slots 2..31 bring 2,970 tasks over
+        the 60 ticks from slot 2's first clock call to the loop's last:
+        the warm-up slots and their compile stay out."""
+        pol, spec, cs, _, key = _setup()
+        rep = serve_loop(pol, spec, cs,
+                         lambda t, k: jnp.full((M,), t, jnp.float32),
+                         T, key, warmup=2, clock=FakeClock())
+        assert rep.tasks_arrived == 6 * sum(range(T))
+        assert rep.tasks_per_sec == 2970 / 60
+
     def test_warmup_clamped_on_tiny_runs(self):
         pol, spec, cs, ar, key = _setup()
         rep = serve_loop(pol, spec, cs, ar, 1, key, warmup=5,
