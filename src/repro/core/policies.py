@@ -49,6 +49,12 @@ from repro.telemetry.profile import phase
 
 Array = jax.Array
 
+# Fill rows of at most this many items (one vreg's lane width) take the
+# dense one-hot path. On a TPU v5e it ran the policy step 1.7-38x faster
+# than the gathers at M = 5 to 200, and 8% slower at M = 4096 (PERF.md):
+# the crossover lies between, unmeasured, so the rule stays at one tile.
+LANE_WIDTH = 128
+
 
 def greedy_fill(
     scores: Array,       # [M] or [B, M] per-item score (negative == take)
@@ -94,6 +100,21 @@ def greedy_fill(
 
     Caps are treated as integer-valued (queue lengths); the budget walk
     takes cap items whenever floor(P/e) >= cap.
+
+    Rows of at most `LANE_WIDTH` (128) items apply each trip's top_k
+    permutation as a dense one-hot select (scope `repro.fill_dense`):
+    `onehot[b, j, m] = idx[b, j] == m`, each gathered value a sum over
+    m of `where(onehot, x, 0)`, each take added back as a sum over j.
+    On a TPU a short row is padded to a lane tile anyway, and an index
+    gather or scatter on it costs several ns per element, while the
+    compares, selects and sums of a [B, k, M] one-hot ride the vector
+    units. Longer rows keep the gathers and the flattened scatter: the
+    one-hot grows as k * M, and at M = 4096 the dense path measured
+    slower (see `LANE_WIDTH`). Both paths return the same counts, bit
+    for bit: every output element is one selected term plus +0.0
+    terms, and adding +0.0 is exact (it could only turn a -0.0 into
+    +0.0, and takes, caps and energies are never -0.0, nor does the
+    sign of a zero score change `score < 0`).
     """
     # The phase scope is profiler metadata only (repro.telemetry
     # §profiling): it labels the fill ops in xprof/Perfetto traces and
@@ -155,28 +176,53 @@ def _greedy_fill(
             stopped = stopped | (live & (fits <= 0.0))
         return (P, stopped), t_j
 
+    if M <= LANE_WIDTH:
+        # Dense one-hot permute (see the docstring).
+        def onehot(idx):  # [B, k, M]: walk position j holds item idx[j]
+            return idx[..., :, None] == jnp.arange(M, dtype=idx.dtype)
+
+        def gather(x, idx):
+            with phase("fill_dense"):
+                return jnp.sum(jnp.where(onehot(idx), x[..., None, :], 0),
+                               axis=-1)
+
+        def scatter_add(t, idx, v):
+            with phase("fill_dense"):
+                return t + jnp.sum(jnp.where(onehot(idx), v[..., :, None], 0),
+                                   axis=-2)
+
+        def mark_done(mkey, idx):
+            with phase("fill_dense"):
+                return jnp.where(jnp.any(onehot(idx), axis=-2), jnp.inf, mkey)
+    else:
+        # Per-lane scatters flattened into ONE row-major scatter on
+        # [B*M]: bit-identical to the per-row vmap formulation (indices
+        # stay unique), one scatter instead of a batched one, and --
+        # because an unbatched scatter is all checkify's OOB rule can
+        # instrument -- the only formulation `analysis.sanitize` can
+        # lift with index_checks enabled.
+        def _rows(i):
+            return (i + M * jnp.arange(B, dtype=i.dtype)[:, None]).ravel()
+
+        def gather(x, idx):
+            return jnp.take_along_axis(x, idx, axis=-1)
+
+        def scatter_add(t, idx, v):
+            return t.ravel().at[_rows(idx)].add(v.ravel()).reshape(B, M)
+
+        def mark_done(mkey, idx):
+            return mkey.ravel().at[_rows(idx)].set(jnp.inf).reshape(B, M)
+
     def walk_chunk(P, stopped, mkey, gate):
         neg, idx = jax.lax.top_k(-mkey, k)  # k smallest keys, stable
         valid = jnp.isfinite(neg) & gate
-        e_s = jnp.take_along_axis(unit_energy, idx, axis=-1)
-        s_s = jnp.take_along_axis(scores, idx, axis=-1)
-        cap_s = jnp.take_along_axis(max_items, idx, axis=-1)
+        e_s = gather(unit_energy, idx)
+        s_s = gather(scores, idx)
+        cap_s = gather(max_items, idx)
         (P, stopped), takes = jax.lax.scan(
             step, (P, stopped), (e_s.T, s_s.T, cap_s.T, valid.T)
         )
         return P, stopped, idx, takes.T
-
-    # Per-lane scatters flattened into ONE row-major scatter on [B*M]:
-    # bit-identical to the per-row vmap formulation (indices stay
-    # unique), one scatter instead of a batched one, and -- because an
-    # unbatched scatter is all checkify's OOB rule can instrument --
-    # the only formulation `analysis.sanitize` can lift with
-    # index_checks enabled.
-    def _rows(i):
-        return (i + M * jnp.arange(B, dtype=i.dtype)[:, None]).ravel()
-
-    def _scatter_add(t, i, v):
-        return t.ravel().at[_rows(i)].add(v.ravel()).reshape(B, M)
 
     stopped0 = jnp.zeros((B,), bool)
     if k == M:
@@ -184,15 +230,14 @@ def _greedy_fill(
         # its exit bookkeeping entirely (the common small-M / fleet-lane
         # case; per-slot cost matches the old argsort+scan fill).
         _, _, idx, takes = walk_chunk(P0, stopped0, mkey0, True)
-        counts = _scatter_add(jnp.zeros_like(scores), idx, takes)
+        counts = scatter_add(jnp.zeros_like(scores), idx, takes)
         return counts[0] if single else counts
 
     def trip(carry):
         P, stopped, take, mkey, act = carry
         P, stopped, idx, takes = walk_chunk(P, stopped, mkey, act[:, None])
-        take = _scatter_add(take, idx, takes)
-        done = mkey.ravel().at[_rows(idx)].set(jnp.inf).reshape(B, M)
-        mkey = jnp.where(act[:, None], done, mkey)
+        take = scatter_add(take, idx, takes)
+        mkey = jnp.where(act[:, None], mark_done(mkey, idx), mkey)
         return P, stopped, take, mkey, active(P, stopped, mkey)
 
     carry = jax.lax.while_loop(
@@ -203,6 +248,18 @@ def _greedy_fill(
     )
     counts = carry[2]
     return counts[0] if single else counts
+
+
+def place_dispatch(like: Array, cols: Array, values: Array) -> Array:
+    """`zeros_like(like).at[arange(M), cols].set(values)`: row m of the
+    [M, W] result holds values[m] at column cols[m] and zeros elsewhere
+    (each type's dispatch at its chosen cloud or route). Built as a
+    dense one-hot select, which writes the same [M, W] bytes as the
+    zeros the scatter would start from and which a TPU runs on its
+    vector units instead of as a scatter; the result is the same, bit
+    for bit."""
+    hit = cols[:, None] == jnp.arange(like.shape[1], dtype=cols.dtype)
+    return jnp.where(hit, values[:, None], 0).astype(like.dtype)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -308,7 +365,7 @@ class CarbonIntensityPolicy:
         d_counts, w = self._fill_all(
             b, c, pe, pc, state.Qe, state.Qc, Pe, Pc
         )
-        d = jnp.zeros_like(state.Qc).at[jnp.arange(spec.M), n1].set(d_counts)
+        d = place_dispatch(state.Qc, n1, d_counts)
         return Action(d=d, w=w)
 
 
@@ -426,7 +483,7 @@ class QueueLengthPolicy:
             sort_key=scores,
             chunk=self.fill_chunk,
         )
-        d = jnp.zeros_like(state.Qc).at[jnp.arange(spec.M), n1].set(counts[0])
+        d = place_dispatch(state.Qc, n1, counts[0])
         return Action(d=d, w=counts[1:].T)
 
 
@@ -493,7 +550,7 @@ class ExactDPPPolicy:
         Qc_n1 = jnp.take_along_axis(state.Qc, n1[:, None], axis=1)[:, 0]
         b = V * Ce * pe + Qc_n1 - state.Qe
         d_counts = bounded_knapsack_min(b, pe, state.Qe, Pe, self.grid)
-        d = jnp.zeros_like(state.Qc).at[jnp.arange(spec.M), n1].set(d_counts)
+        d = place_dispatch(state.Qc, n1, d_counts)
 
         c = dpp.processing_scores(state, pc, Cc, V)
         w = jax.vmap(

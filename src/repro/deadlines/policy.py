@@ -35,6 +35,7 @@ from repro.core.policies import (
     Action,
     LookaheadDPPPolicy,
     greedy_fill,
+    place_dispatch,
 )
 
 # Slack values are capped here before entering sort keys so that +inf
@@ -96,9 +97,7 @@ class SlackThresholdPolicy(LookaheadDPPPolicy):
         d_counts, w = self._fill_all(
             b, c, pe, pc, state.Qe, state.Qc, Pe, Pc
         )
-        d = jnp.zeros_like(state.Qc).at[
-            jnp.arange(spec.M), n1
-        ].set(d_counts)
+        d = place_dispatch(state.Qc, n1, d_counts)
         return Action(d=d, w=w)
 
 
@@ -155,9 +154,7 @@ class EDDPolicy:
             sort_key=scores,
             chunk=self.fill_chunk,
         )
-        d = jnp.zeros_like(state.Qc).at[
-            jnp.arange(spec.M), n1
-        ].set(counts[0])
+        d = place_dispatch(state.Qc, n1, counts[0])
         return Action(d=d, w=counts[1:].T)
 
 
@@ -233,9 +230,7 @@ class WaitAwhilePolicy(LookaheadDPPPolicy):
         d_counts, w = self._fill_all(
             b, c, pe, pc, state.Qe, state.Qc, Pe, Pc
         )
-        d = jnp.zeros_like(state.Qc).at[
-            jnp.arange(spec.M), n1
-        ].set(d_counts)
+        d = place_dispatch(state.Qc, n1, d_counts)
         return Action(d=d, w=w)
 
 

@@ -35,7 +35,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro.core.policies import LookaheadDPPPolicy
+from repro.core.policies import LookaheadDPPPolicy, place_dispatch
 from repro.core.queueing import NetworkSpec, NetworkState
 from repro.network.graph import LinkGraph
 from repro.network.transfer import NetAction
@@ -128,7 +128,7 @@ class NetworkAwareDPPPolicy(LookaheadDPPPolicy):
         d_counts, w = self._fill_all(
             b, c, pe, pc, state.Qe, state.Qc, Pe, Pc
         )
-        dt = jnp.zeros_like(Qt).at[jnp.arange(spec.M), l1].set(d_counts)
+        dt = place_dispatch(Qt, l1, d_counts)
         return NetAction(dt=dt, w=w)
 
 

@@ -51,6 +51,8 @@ PHASES = (
     "policy_score",   # DPP score tables (reference or pallas backend)
     "route_score",    # WAN (type, route, cloud) score tables
     "greedy_fill",    # chunked top_k budget fill
+    "fill_dense",     # the fill's one-hot permute on rows of <= 128 items
+                      # (nested in greedy_fill: which path each fill took)
     "emissions",      # the action's carbon emissions (eq. 5)
     "transfer_step",  # link injection / drain / delivery
     "fault_step",     # fault chain transitions + observation masking

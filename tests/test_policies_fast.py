@@ -5,7 +5,10 @@ float32 numpy transcription of the sequential Algorithm-1 walk across
 every variant (stop_at_first_unfit x literal_edge_budget x sort_key),
 chunk sizes that force multi-trip chunking, batched-lane stacking, and
 the degenerate corners (zero budget, all-nonnegative scores, single
-type, zero caps)."""
+type, zero caps). Rows of at most `LANE_WIDTH` items take the dense
+one-hot path, longer rows the gather path: both are pinned to the
+oracle and to each other, and `place_dispatch` to the scatter it
+replaces."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,13 +22,22 @@ try:  # optional test dep: only the @given property test needs it
 except ImportError:  # pragma: no cover - exercised on lean containers
     HAVE_HYPOTHESIS = False
 
+from repro.core import policies
 from repro.core.policies import (
+    LANE_WIDTH,
     CarbonIntensityPolicy,
     QueueLengthPolicy,
     greedy_fill,
     literal_algorithm1,
+    place_dispatch,
 )
-from repro.core.queueing import NetworkSpec, NetworkState, is_feasible
+from repro.core.queueing import (
+    NetworkSpec,
+    NetworkState,
+    init_state,
+    is_feasible,
+)
+from repro.serve import make_serve_step
 
 f32 = np.float32
 
@@ -92,6 +104,34 @@ def test_fill_matches_sequential_oracle(seed, variant, chunk):
     np.testing.assert_array_equal(want, got)
 
 
+# Row lengths past LANE_WIDTH: the fill keeps its gathers and scatter.
+GATHER_SIZES = (129, 200)
+
+
+@pytest.mark.parametrize("chunk", [3, 64])
+@pytest.mark.parametrize("variant", [v for v, _ in VARIANTS],
+                         ids=[v for v, _ in VARIANTS])
+@pytest.mark.parametrize("M", GATHER_SIZES)
+def test_fill_gather_path_matches_sequential_oracle(M, variant, chunk):
+    """The oracle test on rows too long for the dense path, with budgets
+    large enough that chunk 3 takes several trips."""
+    assert M > LANE_WIDTH
+    kwargs = dict(VARIANTS)[variant]
+    rng = np.random.default_rng(M)
+    scores, e, caps, budget = _instance(rng, M)
+    budget = f32(budget * 40)
+    want = seq_fill(
+        scores, e, caps, budget,
+        stop=kwargs.get("stop_at_first_unfit", True),
+        literal=kwargs.get("literal_edge_budget", False),
+    )
+    got = np.asarray(greedy_fill(
+        jnp.asarray(scores), jnp.asarray(e), jnp.asarray(caps),
+        jnp.asarray(budget), chunk=chunk, **kwargs,
+    ))
+    np.testing.assert_array_equal(want, got)
+
+
 def _fill_property_case(M, budget, seed, variant, chunk, degenerate):
     kwargs = dict(VARIANTS)[variant]
     rng = np.random.default_rng(seed)
@@ -130,6 +170,17 @@ def test_fill_degenerate_corners(variant, degenerate):
     small enough to force multiple trips and M=1 single-type cases."""
     for seed, M, chunk in [(0, 1, 5), (1, 7, 2), (2, 33, 5), (3, 64, 64)]:
         _fill_property_case(M, 250.0, seed, variant, chunk, degenerate)
+
+
+@pytest.mark.parametrize("degenerate", DEGENERATES,
+                         ids=["plain"] + DEGENERATES[1:])
+@pytest.mark.parametrize("variant", [v for v, _ in VARIANTS],
+                         ids=[v for v, _ in VARIANTS])
+def test_fill_degenerate_corners_gather_path(variant, degenerate):
+    """The degenerate corners on rows past LANE_WIDTH (gather path)."""
+    for seed, M, chunk in [(4, GATHER_SIZES[0], 3),
+                           (5, GATHER_SIZES[1], 64)]:
+        _fill_property_case(M, 2500.0, seed, variant, chunk, degenerate)
 
 
 if HAVE_HYPOTHESIS:
@@ -205,6 +256,123 @@ def test_fill_jits_and_vmaps():
         lambda s, e, c, p: greedy_fill(s, e, c, p, chunk=16)
     ))(jnp.asarray(S), jnp.asarray(E), jnp.asarray(C), jnp.asarray(P)))
     np.testing.assert_array_equal(direct, vmapped)
+
+
+def _bits(x):
+    """float32 bit patterns: equal only where the bits are (+0.0 differs
+    from -0.0)."""
+    return np.asarray(x, np.float32).view(np.int32)
+
+
+def _tied_rows(rng, F, B, M):
+    """[F, B, M] fill inputs with tied keys (score/energy takes a few
+    integer values), zero caps, whole rows of non-negative scores and
+    zero budgets."""
+    e = rng.choice(np.array([0.5, 1.0, 2.5, 4.0], f32), (F, B, M))
+    scores = (-rng.integers(-1, 4, (F, B, M)) * e).astype(f32)
+    scores[:, 0] = np.abs(scores[:, 0])
+    caps = rng.integers(0, 30, (F, B, M)).astype(f32)
+    caps[rng.uniform(size=caps.shape) < 0.3] = 0.0
+    budget = rng.uniform(0, 300, (F, B)).astype(f32)
+    budget[:, 1] = 0.0
+    return scores, e, caps, budget
+
+
+@pytest.mark.parametrize("chunk", [3, 64])
+@pytest.mark.parametrize("variant", ["stop", "nostop", "literal",
+                                     "sort_key"])
+def test_fill_dense_and_gather_paths_bit_identical(monkeypatch, variant,
+                                                   chunk):
+    """The dense one-hot path and the gather path return the same bits,
+    jitted and vmapped over lanes as the fleet runs them, with the while
+    loop (chunk < M) and without. Equal takes walked in the same order
+    leave equal budgets, so the counts pin the whole walk."""
+    kwargs = dict(VARIANTS, sort_key=dict(stop_at_first_unfit=False))[variant]
+    rng = np.random.default_rng(chunk)
+    F, B, M = 4, 6, 17
+    assert M <= LANE_WIDTH
+    args = [jnp.asarray(a) for a in _tied_rows(rng, F, B, M)]
+
+    def fill():
+        def one(s, e, c, p):
+            key = s if variant == "sort_key" else None
+            return greedy_fill(s, e, c, p, sort_key=key, chunk=chunk,
+                               **kwargs)
+
+        return np.asarray(jax.jit(jax.vmap(one))(*args))
+
+    dense = fill()
+    monkeypatch.setattr(policies, "LANE_WIDTH", 0)  # every row gathers
+    gather = fill()
+    np.testing.assert_array_equal(_bits(dense), _bits(gather))
+    assert dense.any()
+
+
+@pytest.mark.parametrize("W", [1, 5, LANE_WIDTH, *GATHER_SIZES])
+def test_place_dispatch_equals_the_scatter(W):
+    """The dispatch row's one-hot select equals
+    `zeros.at[arange(M), cols].set(values)` bit for bit, a -0.0 value
+    included, eagerly and jitted under vmap, at row widths up to and
+    past one lane tile."""
+    rng = np.random.default_rng(W)
+    F, M = 3, 7
+    like = jnp.zeros((F, M, W), jnp.float32)
+    cols = jnp.asarray(rng.integers(0, W, (F, M)), jnp.int32)
+    vals = rng.integers(0, 400, (F, M)).astype(f32)
+    vals[:, 0] = -0.0
+    vals = jnp.asarray(vals)
+    want = jax.vmap(
+        lambda z, c, v: z.at[jnp.arange(M), c].set(v))(like, cols, vals)
+    got = jax.jit(jax.vmap(place_dispatch))(like, cols, vals)
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    np.testing.assert_array_equal(
+        _bits(place_dispatch(like[0], cols[0], vals[0])), _bits(want[0]))
+
+
+def _scatter_dispatch(like, cols, values):
+    return jnp.zeros_like(like).at[jnp.arange(like.shape[0]), cols].set(
+        values)
+
+
+def test_served_step_dispatch_bits_equal_the_scatter(monkeypatch):
+    """Inside the compiled served step, slot after slot, the dispatch row
+    and the queues and task counts that follow from it carry the bits of
+    the gather fill with a scattered dispatch row. Only the emissions, a
+    sum whose order follows the fused layout, may differ, in the last
+    bits."""
+    from test_serve import _setup
+
+    def serve(slots=12):
+        pol, spec, cs, ar, key = _setup()
+        rows, exact, emitted = [], [], []
+
+        def spy(*args, **kw):  # the policy, its dispatch row sent out
+            act = pol(*args, **kw)
+            jax.debug.callback(lambda d: rows.append(np.array(d)), act.d)
+            return act
+
+        step = make_serve_step(spy, spec, cs, ar, key)
+        state = init_state(spec.M, spec.N)
+        for t in range(slots):
+            state, metrics = step(state, jnp.int32(t))
+            exact.append([_bits(np.array(x)) for x in
+                          (*jax.tree.leaves(state), *metrics[1:])])
+            emitted.append(float(metrics[0]))
+        jax.effects_barrier()
+        assert len(rows) == slots
+        return rows, exact, emitted
+
+    rows, exact, emitted = serve()
+    monkeypatch.setattr(policies, "LANE_WIDTH", 0)  # every row gathers
+    monkeypatch.setattr(policies, "place_dispatch", _scatter_dispatch)
+    want_rows, want_exact, want_emitted = serve()
+    for got, want in zip(rows, want_rows):
+        np.testing.assert_array_equal(_bits(got), _bits(want))
+    for got, want in zip(exact, want_exact):
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g, w)
+    np.testing.assert_allclose(emitted, want_emitted, rtol=1e-6)
+    assert sum(r.sum() for r in rows) > 0
 
 
 @pytest.mark.parametrize("variant", [v for v, _ in VARIANTS],

@@ -7,7 +7,9 @@ device scopes (`repro.telemetry.profile`).
 * the compiled served step and fleet program carry every slot phase's
   `repro.<phase>` scope in their HLO `op_name` metadata;
 * every scope and span placed under `src/repro` is a canonical name,
-  and every canonical name is placed.
+  and every canonical name is placed;
+* fills of at most `LANE_WIDTH` items compile without a gather or
+  scatter under `repro.greedy_fill`, and longer fills keep theirs.
 """
 import glob
 import os
@@ -22,6 +24,7 @@ from jax.profiler import ProfileData
 
 from repro.configs.fleet_scenarios import build_fleet
 from repro.core import CarbonIntensityPolicy, simulate_fleet
+from repro.core.policies import LANE_WIDTH, greedy_fill
 from repro.core.queueing import init_state
 from repro.serve import make_serve_step, serve_loop
 from repro.telemetry.profile import HOST_SPANS, PHASES, trace_to
@@ -81,7 +84,7 @@ def _deadline_serve_hlo():
 
 
 SLOT = ("arrivals", "carbon", "queue_update", "emissions",
-        "policy_score", "greedy_fill")
+        "policy_score", "greedy_fill", "fill_dense")
 PROGRAMS = {
     "serve_step": (_serve_hlo, SLOT),
     "simulate_fleet": (_fleet_hlo, SLOT),
@@ -91,10 +94,16 @@ PROGRAMS = {
 
 
 @pytest.fixture(scope="module")
-def op_names():
-    """{program: every op_name in its compiled HLO}, compiled once."""
-    return {name: set(re.findall(r'op_name="([^"]*)"', build()))
-            for name, (build, _) in PROGRAMS.items()}
+def hlos():
+    """{program: its compiled HLO text}, compiled once."""
+    return {name: build() for name, (build, _) in PROGRAMS.items()}
+
+
+@pytest.fixture(scope="module")
+def op_names(hlos):
+    """{program: every op_name in its compiled HLO}."""
+    return {name: set(re.findall(r'op_name="([^"]*)"', text))
+            for name, text in hlos.items()}
 
 
 @pytest.mark.parametrize("program,scope", [
@@ -102,6 +111,35 @@ def op_names():
 def test_compiled_program_carries_phase_scope(op_names, program, scope):
     assert any(f"repro.{scope}/" in n for n in op_names[program]), (
         program, scope)
+
+
+_INDEX_OP = re.compile(r' (gather|scatter)\(.*?op_name="([^"]*)"')
+
+
+def _fill_index_ops(hlo):
+    """The opcodes of a compiled program's gathers and scatters that
+    carry `repro.greedy_fill`."""
+    return [op for op, name in _INDEX_OP.findall(hlo)
+            if "repro.greedy_fill/" in name]
+
+
+@pytest.mark.parametrize("program", sorted(PROGRAMS))
+def test_short_fill_rows_compile_without_gather_or_scatter(hlos, program):
+    """M = 5 rows take the dense one-hot path: the fill's permutation is
+    selects and sums, with no index gather or scatter left."""
+    assert _fill_index_ops(hlos[program]) == []
+
+
+def test_long_fill_rows_keep_their_gathers_and_scatter():
+    M = 200
+    assert M > LANE_WIDTH
+    row = jax.ShapeDtypeStruct((6, M), jnp.float32)
+    fn = jax.jit(lambda s, e, c, p: greedy_fill(s, e, c, p, chunk=3))
+    hlo = fn.lower(row, row, row,
+                   jax.ShapeDtypeStruct((6,), jnp.float32)).compile()
+    ops = _fill_index_ops(hlo.as_text())
+    assert "gather" in ops and "scatter" in ops, ops
+    assert "repro.fill_dense" not in hlo.as_text()
 
 
 def _placed(fn_name):
