@@ -7,7 +7,10 @@ into the library: `make_serve_step` compiles exactly one donated-buffer
 step function (the SAME per-slot program as `core.simulator.simulate`'s
 scan body, same PRNG stream splits -- so a served trajectory is bitwise
 the batch trajectory), and `serve_loop` drives it from the host,
-timing every decision.
+timing every decision. The step returns its slot's metrics as one f32
+vector in the order `SERVE_METRICS` (plus `DEADLINE_METRICS` when
+deadline-aware), and the loop reads it to the host once per slot, under
+`serve.pull`: one device-to-host read, not one per scalar.
 
 Observability contract (ISSUE 9 / DESIGN.md §Live observability):
 
@@ -66,6 +69,12 @@ LATENCY_BUCKETS_US = (
     50.0, 100.0, 200.0, 500.0, 1e3, 2e3, 5e3, 1e4, 2e4, 5e4, 1e5, 1e6,
 )
 
+# The entries of the served step's metrics vector, in order; a
+# deadline-aware step appends DEADLINE_METRICS.
+SERVE_METRICS = ("emissions", "arrived", "dispatched", "processed",
+                 "backlog")
+DEADLINE_METRICS = ("missed", "shed")
+
 
 class ServeReport(NamedTuple):
     """End-of-run summary of a `serve_loop` drive. Scalar fields are
@@ -122,14 +131,17 @@ def make_serve_step(policy, spec: NetworkSpec, carbon_source,
     The body is `core.simulator.simulate`'s fault-free scan body with
     the same `jax.random.split(key, 3)` stream assignment, so driving
     it over t = 0..T-1 reproduces the batch trajectory bitwise.
-    metrics = (emissions, arrived, dispatched, processed, backlog),
-    all f32 scalars.
+    `metrics` is one f32 vector of shape [5] in the order
+    `SERVE_METRICS` (emissions, arrived, dispatched, processed,
+    backlog), stacked from the f32 scalars, so each entry is bitwise
+    the scalar it holds and the host reads the slot in one transfer.
 
     With `deadlines` (a DeadlineParams) the carried state becomes the
     pair `(NetworkState, DeadlineState)`, the policy receives the
-    slot's `deadline_view`, and metrics grows `(missed, shed)` -- the
-    same deadline slot dynamics as the batch simulator, so the
-    deadline-aware served trajectory is bitwise the batch one too.
+    slot's `deadline_view`, and metrics grows to [7] with
+    `DEADLINE_METRICS` (missed, shed) -- the same deadline slot
+    dynamics as the batch simulator, so the deadline-aware served
+    trajectory is bitwise the batch one too.
     """
     k_carbon, k_arrive, k_policy = jax.random.split(key, 3)
     if deadlines is not None:
@@ -158,9 +170,9 @@ def make_serve_step(policy, spec: NetworkSpec, carbon_source,
         )
         if deadlines is None:
             nxt = queue_step(state, act, a)
-            return nxt, metrics + (
+            return nxt, jnp.stack(metrics + (
                 jnp.sum(nxt.Qe) + jnp.sum(nxt.Qc),
-            )
+            ))
         d_sum = jnp.sum(act.d, axis=1)
         dstate, admitted, expired, shed = step_deadlines(
             deadlines, dstate, d_sum, a
@@ -170,11 +182,11 @@ def make_serve_step(policy, spec: NetworkSpec, carbon_source,
                 Qe=jnp.maximum(state.Qe - d_sum, 0.0) + admitted - expired,
                 Qc=jnp.maximum(state.Qc - act.w, 0.0) + act.d,
             )
-        return (nxt, dstate), metrics + (
+        return (nxt, dstate), jnp.stack(metrics + (
             jnp.sum(nxt.Qe) + jnp.sum(nxt.Qc),
             jnp.sum(expired),
             jnp.sum(shed),
-        )
+        ))
 
     return jax.jit(step, donate_argnums=0)
 
@@ -382,7 +394,7 @@ def serve_loop(policy, spec: NetworkSpec, carbon_source, arrival_source,
         c1 = clock()
         lat[t] = (c1 - c0) * 1e6
         with span("serve.pull"):
-            pulled = [float(x) for x in metrics]
+            pulled = np.asarray(metrics).tolist()
         with span("serve.bookkeeping"):
             missed_t = shed_t = 0.0
             if deadlines is None:

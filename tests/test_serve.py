@@ -11,6 +11,10 @@ The standing anchors:
   loop calls it in a fixed pattern (once before the loop, twice per
   slot, once after), percentiles exclude exactly the warmup slots and
   follow `np.percentile` linear interpolation;
+* the step returns its slot's metrics as one f32 vector in the order
+  `SERVE_METRICS` (+ `DEADLINE_METRICS`), each entry bitwise the
+  scalar it holds, and the loop's one read of it gives the report a
+  read of each entry would;
 * queue-age is FIFO bookkeeping with known answers on hand-built
   arrival/processing sequences;
 * the live JSONL/Prometheus export parse-validates and the terminal
@@ -31,8 +35,10 @@ from repro.core import (
     UniformArrivals,
     simulate,
 )
-from repro.serve import latency_percentiles, serve_loop
-from repro.serve.loop import _AgeFifo
+from repro.core.queueing import emissions, init_state
+from repro.core.simulator import as_data
+from repro.serve import latency_percentiles, make_serve_step, serve_loop
+from repro.serve.loop import DEADLINE_METRICS, SERVE_METRICS, _AgeFifo
 from repro.telemetry import validate_jsonl, validate_prometheus
 
 jax.config.update("jax_enable_x64", False)
@@ -141,6 +147,124 @@ class TestBatchParity:
             np.float32([e["emissions"] for e in slots]),
             np.asarray(res.emissions),
         )
+
+
+def _deadlines(on):
+    if not on:
+        return None
+    from repro.deadlines import make_deadlines
+
+    return make_deadlines(M, deadline=3.0, shed_on=1.0, headroom=0.5)
+
+
+def _init(deadlines):
+    state = init_state(M, N)
+    if deadlines is None:
+        return state
+    from repro.deadlines.model import init_deadlines
+
+    return state, init_deadlines(M, deadlines.rings.shape[-1])
+
+
+def _bits(x):
+    return np.asarray(x, np.float32).view(np.uint32)
+
+
+class TestMetricsVector:
+    """The served step's metrics are one f32 vector, read once a slot."""
+
+    @pytest.mark.parametrize("deadlines", [False, True],
+                             ids=["no-deadlines", "deadlines"])
+    def test_step_metrics_is_one_f32_vector(self, deadlines):
+        """Each entry is bitwise the scalar computed apart from the same
+        state and slot: emissions from `emissions(...)`, the sums of
+        arrivals, dispatches and processing, the post-step backlog and,
+        with deadlines, the sums of expiries and sheds."""
+        from repro.deadlines.model import deadline_view, step_deadlines
+
+        pol, spec, cs, ar, key = _setup()
+        dl = _deadlines(deadlines)
+        names = SERVE_METRICS + (DEADLINE_METRICS if deadlines else ())
+        k_carbon, k_arrive, k_policy = jax.random.split(key, 3)
+
+        @jax.jit
+        def scalars(state, t):
+            if dl is not None:
+                state, dstate = state
+            spec_t, _ = as_data(spec)
+            Ce, Cc = cs(t, k_carbon)
+            a = ar(t, k_arrive)
+            kw = {} if dl is None else {
+                "deadline_view": deadline_view(dl, dstate)}
+            act = pol(state, spec_t, Ce, Cc, a,
+                      jax.random.fold_in(k_policy, t), **kw)
+            got = {"emissions": emissions(spec_t, act, Ce, Cc),
+                   "arrived": jnp.sum(a), "dispatched": jnp.sum(act.d),
+                   "processed": jnp.sum(act.w)}
+            if dl is not None:
+                _, _, expired, shed = step_deadlines(
+                    dl, dstate, jnp.sum(act.d, axis=1), a)
+                got.update(missed=jnp.sum(expired), shed=jnp.sum(shed))
+            return got
+
+        step = make_serve_step(pol, spec, cs, ar, key, deadlines=dl)
+        state = _init(dl)
+        for t in range(8):
+            want = jax.device_get(scalars(state, jnp.int32(t)))
+            state, metrics = step(state, jnp.int32(t))
+            assert len(jax.tree.leaves(metrics)) == 1
+            assert metrics.dtype == jnp.float32
+            assert metrics.shape == (len(names),)
+            q = state if dl is None else state[0]
+            want["backlog"] = np.float32(jnp.sum(q.Qe) + jnp.sum(q.Qc))
+            np.testing.assert_array_equal(
+                _bits(metrics), _bits([want[n] for n in names]),
+                err_msg=f"slot {t}")
+        if dl is not None:  # the deadline entries were exercised
+            assert np.asarray(state[1].Qd).any()
+
+    @pytest.mark.parametrize("deadlines", [False, True],
+                             ids=["no-deadlines", "deadlines"])
+    def test_report_equals_reading_each_entry(self, deadlines):
+        """`serve_loop`'s one read a slot gives the report a loop that
+        reads each entry of the vector on its own gives, bit for bit."""
+        pol, spec, cs, ar, key = _setup()
+        dl = _deadlines(deadlines)
+        names = SERVE_METRICS + (DEADLINE_METRICS if deadlines else ())
+        rep = serve_loop(pol, spec, cs, ar, T, key, warmup=2,
+                         clock=FakeClock(), deadlines=dl)
+
+        step = make_serve_step(pol, spec, cs, ar, key, deadlines=dl)
+        state = _init(dl)
+        ages = _AgeFifo()
+        totals = dict.fromkeys(SERVE_METRICS + DEADLINE_METRICS, 0.0)
+        backlog, slot_em, queue_age = [], [], []
+        for t in range(T):
+            state, metrics = step(state, jnp.int32(t))
+            got = dict.fromkeys(DEADLINE_METRICS, 0.0)
+            got.update((n, float(metrics[i])) for i, n in enumerate(names))
+            for n in totals:
+                totals[n] += got[n]
+            backlog.append(got["backlog"])
+            slot_em.append(got["emissions"])
+            queue_age.append(ages.update(
+                t, got["arrived"] - got["shed"],
+                got["processed"] + got["missed"]))
+
+        assert rep.tasks_arrived == totals["arrived"]
+        assert rep.tasks_dispatched == totals["dispatched"]
+        assert rep.tasks_processed == totals["processed"]
+        assert rep.total_emissions == totals["emissions"]
+        assert rep.missed_total == totals["missed"]
+        assert rep.shed_total == totals["shed"]
+        np.testing.assert_array_equal(rep.backlog, backlog)
+        np.testing.assert_array_equal(rep.slot_emissions, slot_em)
+        np.testing.assert_array_equal(rep.queue_age, queue_age)
+        for a, b in zip(jax.tree.leaves(rep.final_state),
+                        jax.tree.leaves(state)):
+            np.testing.assert_array_equal(_bits(a), _bits(b))
+        if dl is not None:
+            assert rep.shed_total > 0 or rep.missed_total > 0
 
 
 class TestQueueAge:
